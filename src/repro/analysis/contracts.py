@@ -66,34 +66,12 @@ class ContractResult:
 
 def lower_device_superstep(sess, policy, max_steps: int = 1024):
     """Lower the exact program `_run_device` would dispatch for `policy`
-    on `sess`; returns (compiled, hlo_text).  Mirrors the driver's state
-    construction — if the driver grows a carry element this must grow
-    with it (tests pin the argument shapes)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.policy import build_device_step
-    from repro.obs.telemetry import device_buffers
+    on `sess` (with the driver's own `device_step_args`); returns
+    (compiled, hlo_text)."""
+    from repro.core.policy import build_device_step, device_step_args
 
-    groups = sess.view_groups()
-    step_fn = build_device_step(policy, sess)
-    bn = sess.scheduler.num_blocks
-    tel_cfg = getattr(sess, "telemetry", None)
-    tel_cap = int(tel_cfg.capacity) if tel_cfg is not None else 0
-    state = (jnp.int32(0),
-             tuple(g.values for g in groups),
-             tuple(g.deltas for g in groups),
-             jnp.float32(0), jnp.float32(0), jnp.float32(0),
-             tuple(jnp.zeros(g.capacity, jnp.int32) for g in groups),
-             jnp.zeros(bn, jnp.float32),
-             device_buffers(tel_cap, len(groups)) if tel_cap else ())
-    scales = tuple(g.push_scale for g in groups)
-    tiles = tuple(g.graph.tiles for g in groups)
-    nbrs = tuple(g.graph.nbr_ids for g in groups)
-    ovs = tuple(g.overlay for g in groups)
-    prs = tuple(sess._pair_data(g) for g in groups)
-    key = jax.random.PRNGKey(sess.seed)
-    lowered = step_fn.lower(state, scales, tiles, nbrs, ovs, prs,
-                            jnp.int32(max_steps), key)
+    lowered = build_device_step(policy, sess).lower(
+        *device_step_args(sess, max_steps))
     compiled = lowered.compile()
     return compiled, compiled.as_text()
 
@@ -165,15 +143,13 @@ def mj_spmm_hbm_fetch_bytes(q: int, k: int, capacity: int, vb: int) -> int:
 
 def fused_superstep_vmem_bytes(capacity: int, vb: int,
                                semiring: str) -> int:
-    """Per-grid-cell VMEM for the fused megakernel: pair tile [Vb,Vb] +
-    the per-job [Jb,Vb] state stripes (plus-times: d/base/accumulator;
-    min-plus adds values in+out and the candidate scratch) + the two
-    [Jb] pair counters, fp32 — the same arithmetic its `_pick_job_block`
-    budgets against."""
-    from repro.kernels.fused_superstep.ops import _pick_job_block
-    jb = _pick_job_block(capacity, vb, semiring)
-    stripes = 3 if semiring == "plus_times" else 6
-    return vb * vb * 4 + jb * (stripes * vb + 2) * 4
+    """Per-grid-cell VMEM for the fused megakernel at the job chunk its
+    `_pick_job_block` chooses for `capacity` jobs (`block_vmem_bytes`:
+    padded, double-buffered pair tile and [Jb, Vb] state stripes)."""
+    from repro.kernels.fused_superstep.ops import (_pick_job_block,
+                                                   block_vmem_bytes)
+    return block_vmem_bytes(_pick_job_block(capacity, vb, semiring), vb,
+                            semiring)
 
 
 def priority_pairs_vmem_bytes(vb: int) -> int:

@@ -567,6 +567,35 @@ def build_device_step(policy: SchedulePolicy, sess):
     return jax.jit(step_fn)
 
 
+def device_step_args(sess, budget: int, boost=None) -> tuple:
+    """The arguments of the session's compiled device superstep
+    (`build_device_step`'s step_fn) for a run of at most `budget` (int32)
+    supersteps: (state, scales, tiles, nbrs, overlays, pairs, max_steps,
+    key).  Also what `step_fn.lower(*args)` takes to inspect the
+    compiled program."""
+    groups = sess.view_groups()
+    bn = sess.scheduler.num_blocks
+    tel_cfg = getattr(sess, "telemetry", None)
+    tel_cap = int(tel_cfg.capacity) if tel_cfg is not None else 0
+    state = (jnp.int32(0),
+             tuple(g.values for g in groups),
+             tuple(g.deltas for g in groups),
+             jnp.float32(0), jnp.float32(0), jnp.float32(0),
+             tuple(jnp.zeros(g.capacity, jnp.int32) for g in groups),
+             jnp.zeros(bn, jnp.float32) if boost is None
+             else jnp.asarray(boost, jnp.float32),
+             device_buffers(tel_cap, len(groups)) if tel_cap else ())
+    key = jax.random.fold_in(jax.random.PRNGKey(sess.seed),
+                             sess.scheduler._step)
+    return (state,
+            tuple(g.push_scale for g in groups),
+            tuple(g.graph.tiles for g in groups),
+            tuple(g.graph.nbr_ids for g in groups),
+            tuple(g.overlay for g in groups),
+            tuple(sess._pair_data(g) for g in groups),
+            jnp.int32(budget), key)
+
+
 def _run_device(policy: SchedulePolicy, sess,
                 max_supersteps: int) -> RunMetrics:
     """Device driver: call the cached jitted step, sync once per chunk.
@@ -584,31 +613,15 @@ def _run_device(policy: SchedulePolicy, sess,
         return run_device_2d(policy, sess, max_supersteps)
     groups = sess.view_groups()
     step_fn = sess._device_step_fn(policy)
-    boost = sess._consume_dirty_boost()
-    bn = sess.scheduler.num_blocks
     tel_cfg = getattr(sess, "telemetry", None)
     tel_cap = int(tel_cfg.capacity) if tel_cfg is not None else 0
     trace = getattr(sess, "trace", None)
     trace = trace if trace is not None and trace.enabled else None
-    state = (jnp.int32(0),
-             tuple(g.values for g in groups),
-             tuple(g.deltas for g in groups),
-             jnp.float32(0), jnp.float32(0), jnp.float32(0),
-             tuple(jnp.zeros(g.capacity, jnp.int32) for g in groups),
-             jnp.zeros(bn, jnp.float32) if boost is None
-             else jnp.asarray(boost, jnp.float32),
-             device_buffers(tel_cap, len(groups)) if tel_cap else ())
-    scales = tuple(g.push_scale for g in groups)
-    tiles = tuple(g.graph.tiles for g in groups)
-    nbrs = tuple(g.graph.nbr_ids for g in groups)
-    ovs = tuple(g.overlay for g in groups)
-    prs = tuple(sess._pair_data(g) for g in groups)
     # the budget the device compares against must be the SAME clamped
     # value the host loop tests, or a >int32 budget could spin forever
     budget = int(min(max_supersteps, np.iinfo(np.int32).max))
-    max_steps = jnp.int32(budget)
-    key = jax.random.fold_in(jax.random.PRNGKey(sess.seed),
-                             sess.scheduler._step)
+    state, scales, tiles, nbrs, ovs, prs, max_steps, key = \
+        device_step_args(sess, budget, sess._consume_dirty_boost())
     m = RunMetrics()
     while True:
         t_chunk = trace.now_us() if trace else 0.0

@@ -120,12 +120,14 @@ def push_min_one(values: jnp.ndarray, deltas: jnp.ndarray,
     d_sel = jnp.where(consumed, deltas, jnp.inf)[sel_ids]   # [q, Vb]
     d_sel = jnp.where(sel_mask[:, None] > 0, d_sel, jnp.inf)
     deltas = jnp.where(consumed, jnp.inf, deltas)
-    t_sel = tiles[sel_ids]                                   # [q, K, Vb, Vb]
-    nbr_sel = nbr_ids[sel_ids]                               # [q, K]
 
-    def body(carry, inp):
+    # one ELL slot k per scan step, gathered inside the step: staging all
+    # [q, K, Vb, Vb] selected tiles at once does not fit a 16 GB chip
+    # at deployment size (q=282, K=498, Vb=64 needs 2 x 4.6 GB)
+    def body(carry, k):
         values, deltas = carry
-        t_k, dst_k = inp                                     # [q,Vb,Vb], [q]
+        t_k = tiles[sel_ids, k]                              # [q, Vb, Vb]
+        dst_k = nbr_ids[sel_ids, k]                          # [q]
         contrib = jnp.min(d_sel[:, :, None] + t_k, axis=1)   # [q, Vb]
         old = values[dst_k]
         values = values.at[dst_k].min(contrib)
@@ -135,8 +137,7 @@ def push_min_one(values: jnp.ndarray, deltas: jnp.ndarray,
         return (values, deltas), None
 
     (values, deltas), _ = jax.lax.scan(
-        body, (values, deltas),
-        (jnp.swapaxes(t_sel, 0, 1), jnp.swapaxes(nbr_sel, 0, 1)))
+        body, (values, deltas), jnp.arange(tiles.shape[1]))
     values, deltas = overlay_push_min(values, deltas, d_sel, overlay, sel_ids)
     return values, deltas
 
@@ -159,10 +160,11 @@ def shared_push_fn(semiring: str, push_one, use_pallas: bool):
     pairs) with `pairs` the view's `graph.BlockPairs`:
 
       use_pallas=True   the fused_superstep Pallas megakernel sweeps the
-                        destination-sorted pairs (select/stage/push/
-                        priority fused per dst block); the overlay
-                        ride-along stays in jnp on the PRE-consumption
-                        deltas, exactly like every other push path.
+                        destination-sorted pairs (select/stage/push
+                        fused per dst block); the overlay ride-along
+                        stays in jnp on the PRE-consumption deltas,
+                        exactly like every other push path.  Needs the
+                        pair view: there is no ELL fallback.
       use_pallas=False  plus-times emulates the same pair sweep in jnp
                         with a per-(job, pair) einsum + scatter-add.
                         Deliberately NOT `pairs.dense_op`: a [J, N] @
@@ -172,8 +174,8 @@ def shared_push_fn(semiring: str, push_one, use_pallas: bool):
                         guarantees.  min-plus keeps the vmapped per-job
                         `push_one` (its sequential min-scan is the
                         bitwise anchor the fixpoint tests pin).
-      pairs=None        falls back to the vmapped `push_one` (block-ELL
-                        staging), for callers without a pair view.
+      pairs=None        (jnp route only) falls back to the vmapped
+                        `push_one` (block-ELL staging).
     """
     vm = jax.vmap(push_one, in_axes=(0, 0, None, None, None, None, 0, None))
     if use_pallas:
@@ -181,9 +183,6 @@ def shared_push_fn(semiring: str, push_one, use_pallas: bool):
 
         def fn(values, deltas, tiles, nbr_ids, sel, msk, scales, overlay,
                pairs):
-            if pairs is None:         # no pair view: block-ELL fallback
-                return vm(values, deltas, tiles, nbr_ids, sel, msk, scales,
-                          overlay)
             del tiles, nbr_ids        # the pair view replaces ELL staging
             # the overlay must see the PRE-consumption deltas, gathered
             # before the kernel zeroes/infs them

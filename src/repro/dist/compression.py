@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -84,7 +83,7 @@ def make_compressed_grad_fn(mesh: Mesh, loss_fn: Callable[..., jax.Array], *,
                 jax.tree_util.tree_unflatten(treedef, out_g),
                 jax.tree_util.tree_unflatten(treedef, out_e))
 
-    return shard_map(_local, mesh=mesh,
-                     in_specs=(P(), P(), P(axis)),
-                     out_specs=(P(), P(), P()),
-                     check_rep=False)
+    return jax.shard_map(_local, mesh=mesh,
+                         in_specs=(P(), P(), P(axis)),
+                         out_specs=(P(), P(), P()),
+                         check_vma=False)

@@ -148,7 +148,6 @@ def shard_session(mesh: Mesh, session, axis_name: Optional[str] = None,
             first=_replicated(mesh, bp.first),
             last=_replicated(mesh, bp.last),
             src_nnz=_replicated(mesh, bp.src_nnz),
-            dst_touched=_replicated(mesh, bp.dst_touched),
             tiles=_replicated(mesh, bp.tiles),
             dense_op=None)
     return session

@@ -55,7 +55,6 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.algorithms.base import PLUS_TIMES
@@ -198,7 +197,6 @@ class PairShards:
                  that actually scales down 1/S — the capacity win)
       src_nnz    [B_N] int32  GLOBAL per-source real-pair counts (the
                  tile_pair_loads accounting is shard-agnostic)
-      dst_touched_local [S, B_loc] bool  per-shard touched destinations
     """
 
     num_shards: int
@@ -213,11 +211,10 @@ class PairShards:
     last: jnp.ndarray
     tiles: jnp.ndarray
     src_nnz: jnp.ndarray
-    dst_touched_local: jnp.ndarray
 
     def tree_flatten(self):
         leaves = (self.src, self.dst_local, self.first, self.last,
-                  self.tiles, self.src_nnz, self.dst_touched_local)
+                  self.tiles, self.src_nnz)
         aux = (self.num_shards, self.pair_cap, self.block_size,
                self.num_blocks, self.blocks_per_shard, self.fill)
         return leaves, aux
@@ -239,9 +236,9 @@ def partition_block_pairs(bp, n_shards: int, fill: float) -> PairShards:
         raise ValueError(
             f"B_N={bn} does not divide into {n_shards} block shards")
     b_loc = bn // n_shards
-    src, dst, first, last, tiles, touched = map(
+    src, dst, first, last, tiles = map(
         np.asarray, jax.device_get((bp.src, bp.dst, bp.first, bp.last,
-                                    bp.tiles, bp.dst_touched)))
+                                    bp.tiles)))
     bounds = np.searchsorted(dst, np.arange(n_shards + 1) * b_loc,
                              side="left")
     pm = max(1, int(np.max(np.diff(bounds))))
@@ -250,7 +247,6 @@ def partition_block_pairs(bp, n_shards: int, fill: float) -> PairShards:
     s_first = np.zeros((n_shards, pm), np.int32)
     s_last = np.zeros((n_shards, pm), np.int32)
     s_tiles = np.full((n_shards, pm, vb, vb), fill, np.float32)
-    s_touch = np.zeros((n_shards, b_loc), bool)
     for s in range(n_shards):
         lo, hi = int(bounds[s]), int(bounds[s + 1])
         k = hi - lo
@@ -261,14 +257,12 @@ def partition_block_pairs(bp, n_shards: int, fill: float) -> PairShards:
             s_first[s, :k] = first[lo:hi]
             s_last[s, :k] = last[lo:hi]
             s_tiles[s, :k] = tiles[lo:hi]
-        s_touch[s] = touched[s * b_loc:(s + 1) * b_loc]
     return PairShards(
         num_shards=n_shards, pair_cap=pm, block_size=vb, num_blocks=bn,
         blocks_per_shard=b_loc, fill=float(fill),
         src=jnp.asarray(s_src), dst_local=jnp.asarray(s_dst),
         first=jnp.asarray(s_first), last=jnp.asarray(s_last),
-        tiles=jnp.asarray(s_tiles), src_nnz=bp.src_nnz,
-        dst_touched_local=jnp.asarray(s_touch))
+        tiles=jnp.asarray(s_tiles), src_nnz=bp.src_nnz)
 
 
 def place_pair_shards(spec: Mesh2DSpec, ps: PairShards,
@@ -284,8 +278,7 @@ def place_pair_shards(spec: Mesh2DSpec, ps: PairShards,
         ps,
         src=put(ps.src, P(ba)), dst_local=put(ps.dst_local, P(ba)),
         first=put(ps.first, P(ba)), last=put(ps.last, P(ba)),
-        tiles=put(ps.tiles, P(ba)), src_nnz=put(ps.src_nnz, P()),
-        dst_touched_local=put(ps.dst_touched_local, P(ba)))
+        tiles=put(ps.tiles, P(ba)), src_nnz=put(ps.src_nnz, P()))
 
 
 def pair_shards_spec(spec: Mesh2DSpec, blocks_sharded: bool) -> PairShards:
@@ -294,8 +287,7 @@ def pair_shards_spec(spec: Mesh2DSpec, blocks_sharded: bool) -> PairShards:
     return PairShards(
         num_shards=0, pair_cap=0, block_size=0, num_blocks=0,
         blocks_per_shard=0, fill=0.0,
-        src=ba, dst_local=ba, first=ba, last=ba, tiles=ba,
-        src_nnz=P(), dst_touched_local=ba)
+        src=ba, dst_local=ba, first=ba, last=ba, tiles=ba, src_nnz=P())
 
 
 # ---------------------------------------------------------------------------
@@ -479,7 +471,7 @@ def _overlay_min_local(values, d_sel, ov, sel, boff, b_loc: int,
 
 def _apply_pairs_local(semiring: str, values, deltas_base, raw, d_wide,
                        d_sel, sel, ps_src, ps_dstl, ps_first, ps_last,
-                       ps_tiles, ps_touched, scales, msk, overlay, boff,
+                       ps_tiles, scales, msk, overlay, boff,
                        b_loc: int, shared: bool, use_pallas: bool):
     """One shard's pair run: push the exchanged frontier through the
     LOCAL dst-sorted pair slice (+ the overlay ride-along), with the
@@ -497,13 +489,12 @@ def _apply_pairs_local(semiring: str, values, deltas_base, raw, d_wide,
                 fused_superstep_call)
             from repro.kernels.common import resolve_interpret
             from repro.kernels.fused_superstep.ops import _pick_job_block
-            out, _, _ = fused_superstep_call(
+            out = fused_superstep_call(
                 ps_src, ps_dstl, ps_first, ps_last, d_push, deltas_base,
                 ps_tiles, semiring=semiring,
                 job_block=_pick_job_block(values.shape[0],
                                           values.shape[-1], semiring),
                 interpret=resolve_interpret(None))
-            out = jnp.where(ps_touched[None, :, None], out, deltas_base)
         else:
             contrib = jnp.einsum("jpv,pvw->jpw", d_push[:, ps_src, :],
                                  ps_tiles)
@@ -518,14 +509,12 @@ def _apply_pairs_local(semiring: str, values, deltas_base, raw, d_wide,
         from repro.kernels.fused_superstep.kernel import fused_superstep_call
         from repro.kernels.common import resolve_interpret
         from repro.kernels.fused_superstep.ops import _pick_job_block
-        vo, do, _, _ = fused_superstep_call(
+        v1, d1 = fused_superstep_call(
             ps_src, ps_dstl, ps_first, ps_last, d_wide, deltas_base,
             ps_tiles, values=values, semiring=semiring,
             job_block=_pick_job_block(values.shape[0], values.shape[-1],
                                       semiring),
             interpret=resolve_interpret(None))
-        v1 = jnp.where(ps_touched[None, :, None], vo, values)
-        d1 = jnp.where(ps_touched[None, :, None], do, deltas_base)
         v2 = _overlay_min_local(v1, d_sel, overlay, sel, boff, b_loc, shared)
         improved = v2 < v1
         return v2, jnp.minimum(d1, jnp.where(improved, v2, jnp.inf))
@@ -737,8 +726,7 @@ def build_device_step_2d(policy, sess, spec: Mesh2DSpec):
             v2, d2 = _apply_pairs_local(
                 g.semiring, vs[gi], base, raw, d_wide, d_sel, sel,
                 prs[gi].src[0], prs[gi].dst_local[0], prs[gi].first[0],
-                prs[gi].last[0], prs[gi].tiles[0],
-                prs[gi].dst_touched_local[0], scales[gi], msk, ovs[gi],
+                prs[gi].last[0], prs[gi].tiles[0], scales[gi], msk, ovs[gi],
                 boff, b_loc, shared, use_pallas)
             keep = keeps[gi]
             new_vs.append(jnp.where(keep, v2, vs[gi]))
@@ -820,14 +808,14 @@ def build_device_step_2d(policy, sess, spec: Mesh2DSpec):
         bsp = P(ba) if lay.blocks_sharded else P()
         prs_specs.append(dataclasses.replace(
             sess._pair_shards(g), src=bsp, dst_local=bsp, first=bsp,
-            last=bsp, tiles=bsp, src_nnz=P(), dst_touched_local=bsp))
+            last=bsp, tiles=bsp, src_nnz=P()))
     prs_specs = tuple(prs_specs)
     scales_specs = tuple(spec.jobs_spec(lay) for lay in lays)
     in_specs = (state_spec, scales_specs, graph_specs, graph_specs,
                 ovs_specs, prs_specs, P(), P())
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=spec.mesh, in_specs=in_specs,
-        out_specs=(state_spec, P()), check_rep=False))
+        out_specs=(state_spec, P()), check_vma=False))
 
 
 def compute_pairs_local(alg, values, deltas):
@@ -949,8 +937,8 @@ def shared_push_fn_2d(spec: Mesh2DSpec, grp, use_pallas: bool):
             return _apply_pairs_local(
                 semiring, values, base, raw, d_wide, d_sel, sel,
                 ps.src[0], ps.dst_local[0], ps.first[0], ps.last[0],
-                ps.tiles[0], ps.dst_touched_local[0], scales, msk,
-                overlay, boff, b_loc, True, use_pallas)
+                ps.tiles[0], scales, msk, overlay, boff, b_loc, True,
+                use_pallas)
 
         st = spec.state_spec(lay)
         ov_spec = TileOverlaySpec(ov_cap)
@@ -959,11 +947,11 @@ def shared_push_fn_2d(spec: Mesh2DSpec, grp, use_pallas: bool):
             ps_spec, num_shards=ps_aux[0], pair_cap=ps_aux[1],
             block_size=ps_aux[2], num_blocks=ps_aux[3],
             blocks_per_shard=ps_aux[4], fill=ps_aux[5])
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=spec.mesh,
             in_specs=(st, st, P(), P(), spec.jobs_spec(lay), ov_spec,
                       ps_spec),
-            out_specs=(st, st), check_rep=False))
+            out_specs=(st, st), check_vma=False))
 
     def fn(values, deltas, tiles, nbr_ids, sel, msk, scales, overlay,
            pairs):
@@ -999,8 +987,8 @@ def indep_push_fn_2d(spec: Mesh2DSpec, grp):
             return _apply_pairs_local(
                 semiring, values, base, raw, d_wide, d_sel, sel,
                 ps.src[0], ps.dst_local[0], ps.first[0], ps.last[0],
-                ps.tiles[0], ps.dst_touched_local[0], scales, msk,
-                overlay, boff, b_loc, False, False)
+                ps.tiles[0], scales, msk, overlay, boff, b_loc, False,
+                False)
 
         st = spec.state_spec(lay)
         jsp = spec.jobs_spec(lay)
@@ -1010,10 +998,10 @@ def indep_push_fn_2d(spec: Mesh2DSpec, grp):
             ps_spec, num_shards=ps_aux[0], pair_cap=ps_aux[1],
             block_size=ps_aux[2], num_blocks=ps_aux[3],
             blocks_per_shard=ps_aux[4], fill=ps_aux[5])
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=spec.mesh,
             in_specs=(st, st, jsp, jsp, jsp, ov_spec, ps_spec),
-            out_specs=(st, st), check_rep=False))
+            out_specs=(st, st), check_vma=False))
 
     def fn(values, deltas, tiles, nbr_ids, sel, msk, scales, overlay,
            pairs):

@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,10 +68,10 @@ def make_pipelined_loss(mesh: Mesh, stage_fn: Callable, loss_fn: Callable,
         full = outs.reshape((outs.shape[0] * outs.shape[1],) + outs.shape[2:])
         return loss_fn(full, y)
 
-    sharded = shard_map(_body, mesh=mesh,
-                        in_specs=(P(axis_name), P(), P()),
-                        out_specs=P(),
-                        check_rep=False)
+    sharded = jax.shard_map(_body, mesh=mesh,
+                            in_specs=(P(axis_name), P(), P()),
+                            out_specs=P(),
+                            check_vma=False)
 
     def pipelined(params, x, y):
         batch = x.shape[0]

@@ -151,9 +151,6 @@ class BlockPairs:
       src_nnz [B_N] int32   real pairs per SOURCE block — staging block b
                             moves src_nnz[b] * Vb^2 * 4 real adjacency
                             bytes, the quantity `tile_pair_loads` accounts
-      dst_touched [B_N] bool  blocks that appear as a destination (pairs
-                              never write the others; callers pass state
-                              through for them)
       tiles [P, Vb, Vb] f32   contiguous dst-sorted copy of the pair tiles
       dense_op  [B_N*Vb, B_N*Vb] f32 or None — the full adjacency operator
                 (row u, col v = weight of edge u->v), built only for
@@ -178,13 +175,12 @@ class BlockPairs:
     first: jnp.ndarray
     last: jnp.ndarray
     src_nnz: jnp.ndarray
-    dst_touched: jnp.ndarray
     tiles: jnp.ndarray
     dense_op: Optional[jnp.ndarray] = None
 
     def tree_flatten(self):
         leaves = (self.src, self.dst, self.slot, self.first, self.last,
-                  self.src_nnz, self.dst_touched, self.tiles, self.dense_op)
+                  self.src_nnz, self.tiles, self.dense_op)
         aux = (self.num_pairs, self.block_size, self.num_blocks)
         return leaves, aux
 
@@ -226,7 +222,6 @@ def build_block_pairs(g: BlockedGraph, *,
             slot=jnp.zeros(1, jnp.int32), first=jnp.ones(1, jnp.int32),
             last=jnp.ones(1, jnp.int32),
             src_nnz=jnp.asarray(src_nnz),
-            dst_touched=jnp.zeros(bn, bool),
             tiles=jnp.full((1, vb, vb), g.fill, jnp.float32))
     order = np.lexsort((sb, db))          # dst-major, src ascending within
     sb, db, slot = sb[order], db[order], slot[order]
@@ -234,8 +229,6 @@ def build_block_pairs(g: BlockedGraph, *,
     first[1:] = (db[1:] != db[:-1]).astype(np.int32)
     last = np.ones(len(sb), np.int32)
     last[:-1] = first[1:]
-    touched = np.zeros(bn, bool)
-    touched[db] = True
     tiles = g.tiles[jnp.asarray(sb), jnp.asarray(slot)]   # [P, Vb, Vb] copy
     dense_op = None
     density = len(sb) / float(bn * bn)
@@ -249,7 +242,7 @@ def build_block_pairs(g: BlockedGraph, *,
         src=jnp.asarray(sb, jnp.int32), dst=jnp.asarray(db, jnp.int32),
         slot=jnp.asarray(slot, jnp.int32),
         first=jnp.asarray(first), last=jnp.asarray(last),
-        src_nnz=jnp.asarray(src_nnz), dst_touched=jnp.asarray(touched),
+        src_nnz=jnp.asarray(src_nnz),
         tiles=tiles, dense_op=dense_op)
 
 

@@ -10,25 +10,16 @@ import jax.numpy as jnp
 
 
 def fused_superstep_ref(src, dst, first, last, d, base, tiles, *,
-                        values=None, semiring: str = "plus_times",
-                        tolerance: float = 1e-6):
+                        values=None, semiring: str = "plus_times"):
     del first, last
     j, bn, vb = d.shape
     if semiring == "plus_times":
         contrib = jnp.einsum("jpv,pvw->jpw", d[:, src, :], tiles)
-        out = base.at[:, dst, :].add(contrib, mode="drop")
-        a = jnp.abs(out)
-        pr = jnp.where(a >= tolerance, a, 0.0)
-        nu = jnp.sum(pr > 0.0, axis=-1).astype(jnp.float32)
-        ps = jnp.sum(pr, axis=-1)
-        return out, nu, ps
+        return base.at[:, dst, :].add(contrib, mode="drop")
     assert values is not None
     cand_p = jnp.min(d[:, src, :, None] + tiles[None], axis=2)  # [J, P, Vb]
     cand = jnp.full((j, bn, vb), jnp.inf).at[:, dst, :].min(
         cand_p, mode="drop")
     v_new = jnp.minimum(values, cand)
     d_new = jnp.minimum(base, jnp.where(v_new < values, v_new, jnp.inf))
-    pr = jnp.where(jnp.isfinite(d_new), 1.0 / (1.0 + d_new), 0.0)
-    nu = jnp.sum(pr > 0.0, axis=-1).astype(jnp.float32)
-    ps = jnp.sum(pr, axis=-1)
-    return v_new, d_new, nu, ps
+    return v_new, d_new

@@ -1,8 +1,8 @@
 """Distribution correctness, run in subprocesses with 8 host devices:
 
-1. the paper's fused engine with the production sharding (jobs over `model`,
-   vertex blocks over `data`) reaches the same PageRank fixpoint as the
-   single-device run;
+1. a GraphSession under the fused device policy, placed on the 2D mesh
+   (jobs over one axis, vertex blocks over the other), reaches the same
+   PageRank fixpoint as the single-device run;
 2. a checkpoint saved under one mesh restores onto a different mesh
    (elastic re-shard) bit-exactly.
 """
@@ -15,38 +15,30 @@ ENGINE_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
-import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+import jax
 from repro.algorithms import PageRank, PersonalizedPageRank
-from repro.core import ConcurrentEngine, make_run
+from repro.core import Fused, GraphSession
+from repro.dist.mesh2d import make_mesh2d
 from repro.graph import rmat_graph
 
 csr = rmat_graph(256, 5, seed=21)
 algs = [PageRank(), PageRank(damping=0.7),
         PersonalizedPageRank(source=3), PersonalizedPageRank(source=99)]
 
-# single-device reference
-run_ref = make_run(algs, csr, block_size=16)
-eng_ref = ConcurrentEngine(run_ref, seed=0)
-m_ref = eng_ref.run_fused(20000)
-assert m_ref.converged
-ref = eng_ref.results()
 
-# sharded: jobs over model, blocks over data
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-run = make_run(algs, csr, block_size=16)
-jobs_sh = NamedSharding(mesh, P("model", "data", None))
-tile_sh = NamedSharding(mesh, P("data", None, None, None))
-run.values = jax.device_put(run.values, jobs_sh)
-run.deltas = jax.device_put(run.deltas, jobs_sh)
-g = run.graph
-g.tiles = jax.device_put(g.tiles, tile_sh)
-g.nbr_ids = jax.device_put(g.nbr_ids, NamedSharding(mesh, P("data", None)))
-eng = ConcurrentEngine(run, seed=0)
-with mesh:
-    m = eng.run_fused(20000)
-assert m.converged
-out = eng.results()
+def run(mesh):
+    sess = GraphSession(csr, 16, capacity=len(algs), seed=0)
+    handles = [sess.submit(a) for a in algs]
+    m = sess.run(Fused(), 20000, mesh=mesh)
+    assert m.converged
+    return sess, np.stack([sess.result(h) for h in handles])
+
+
+_, ref = run(None)                     # single-device reference
+# sharded: jobs over one mesh axis, vertex blocks over the other
+sess, out = run(make_mesh2d(jobs=2, blocks=4))
+assert sess._mesh2d is not None
+assert len(sess.view_groups()[0].values.sharding.device_set) == 8
 np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-7)
 print("DIST-ENGINE-OK")
 """

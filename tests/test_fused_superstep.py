@@ -1,8 +1,8 @@
 """Fused superstep megakernel + sparse block-pair tests.
 
 Covers the bugfix acceptance criteria:
-  * the megakernel (select -> stage -> multi-job push -> priority pairs in
-    ONE Pallas program over destination-sorted BlockPairs) matches the jnp
+  * the megakernel (select -> stage -> multi-job push in ONE Pallas
+    program over destination-sorted BlockPairs) matches the jnp
     oracle — bitwise for min-plus, float-tolerance for plus-times;
   * `BlockPairs` construction invariants (dst-sorted runs, first/last
     flags, src_nnz real-byte accounting, dense_op faithfulness, the
@@ -12,8 +12,9 @@ Covers the bugfix acceptance criteria:
     kernel entry point (the silent-interpret bug regression);
   * scatter drop-mode parity: sentinel (out-of-range) neighbour ids are
     DROPPED identically by the kernel route and the vmapped engine push;
-  * prime job counts degrade the job chunk to 1 under a tight VMEM
-    budget and the kernel still validates inside that budget;
+  * job chunks are J or a multiple of 8 (the TPU block tiling); a tight
+    VMEM budget degrades the chunk and the kernel still validates;
+  * pair lists longer than one call's SMEM budget chain exactly;
   * padded selection slots aliasing block 0 must not re-push block 0;
   * `tile_pair_loads` (real adjacency bytes) agrees between the host and
     device drivers and across the kernel/jnp push routes.
@@ -71,18 +72,14 @@ def test_fused_kernel_matches_ref_plus_times(j, jb):
     rng = np.random.default_rng(j * 10 + (jb or 0))
     d, base, _ = _rand_state(rng, j, g.num_blocks, g.block_size,
                              "plus_times")
-    out, nu, ps = fused_superstep_call(
+    out = fused_superstep_call(
         bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
-        semiring="plus_times", tolerance=1e-6, job_block=jb,
-        interpret=True)
-    r_out, r_nu, r_ps = fused_superstep_ref(
+        semiring="plus_times", job_block=jb, interpret=True)
+    r_out = fused_superstep_ref(
         bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
-        semiring="plus_times", tolerance=1e-6)
-    t = np.asarray(bp.dst_touched)
-    np.testing.assert_allclose(np.asarray(out)[:, t], np.asarray(r_out)[:, t],
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(nu)[:, t], np.asarray(r_nu)[:, t])
-    np.testing.assert_allclose(np.asarray(ps)[:, t], np.asarray(r_ps)[:, t],
+        semiring="plus_times")
+    # untouched destination blocks pass through the aliased base
+    np.testing.assert_allclose(np.asarray(out), np.asarray(r_out),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -95,18 +92,45 @@ def test_fused_kernel_matches_ref_min_plus_bitwise(jb):
     rng = np.random.default_rng(7 + (jb or 0))
     d, base, vals = _rand_state(rng, j, g.num_blocks, g.block_size,
                                 "min_plus")
-    vo, do, nu, ps = fused_superstep_call(
+    vo, do = fused_superstep_call(
         bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
         values=vals, semiring="min_plus", job_block=jb, interpret=True)
-    r_vo, r_do, r_nu, r_ps = fused_superstep_ref(
+    r_vo, r_do = fused_superstep_ref(
         bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
         values=vals, semiring="min_plus")
-    t = np.asarray(bp.dst_touched)
-    np.testing.assert_array_equal(np.asarray(vo)[:, t], np.asarray(r_vo)[:, t])
-    np.testing.assert_array_equal(np.asarray(do)[:, t], np.asarray(r_do)[:, t])
-    np.testing.assert_array_equal(np.asarray(nu)[:, t], np.asarray(r_nu)[:, t])
-    np.testing.assert_allclose(np.asarray(ps)[:, t], np.asarray(r_ps)[:, t],
-                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(vo), np.asarray(r_vo))
+    np.testing.assert_array_equal(np.asarray(do), np.asarray(r_do))
+
+
+@pytest.mark.parametrize("semiring", ["plus_times", "min_plus"])
+def test_fused_kernel_pair_chunks_chain_exactly(monkeypatch, semiring):
+    """A pair list longer than one call's SMEM budget is swept by a chain
+    of calls whose chunk edges split dst runs: the chained result equals
+    the oracle (min-plus bitwise)."""
+    from repro.kernels.fused_superstep import kernel as fk
+    g, bp = _pairs_for(semiring, n=300, deg=6)
+    chunk = 7                       # splits most dst runs across calls
+    assert bp.num_pairs > 3 * chunk
+    monkeypatch.setattr(fk, "PAIR_CHUNK", chunk)
+    fk._fused_jit.clear_cache()
+    rng = np.random.default_rng(21)
+    d, base, vals = _rand_state(rng, 4, g.num_blocks, g.block_size,
+                                semiring)
+    try:
+        got = fused_superstep_call(
+            bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
+            values=vals, semiring=semiring, interpret=True)
+    finally:
+        fk._fused_jit.clear_cache()
+    want = fused_superstep_ref(
+        bp.src, bp.dst, bp.first, bp.last, d, base, bp.tiles,
+        values=vals, semiring=semiring)
+    if semiring == "min_plus":
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("semiring", ["plus_times", "min_plus"])
@@ -176,9 +200,6 @@ def test_block_pairs_invariants():
     # src_nnz counts real pairs per SOURCE block (tile_pair_loads unit)
     np.testing.assert_array_equal(np.asarray(bp.src_nnz),
                                   msk.sum(axis=1).astype(np.int32))
-    np.testing.assert_array_equal(
-        np.asarray(bp.dst_touched),
-        np.isin(np.arange(g.num_blocks), dst))
     # pair tiles are the real ELL tiles, in pair order
     np.testing.assert_array_equal(np.asarray(bp.tiles),
                                   np.asarray(g.tiles)[src, slot])
@@ -203,7 +224,6 @@ def test_block_pairs_edgeless_pad_pair_is_inert():
         bp = build_block_pairs(g)
         assert bp.num_pairs == 1
         assert int(np.asarray(bp.src_nnz).sum()) == 0
-        assert not np.asarray(bp.dst_touched).any()
         rng = np.random.default_rng(0)
         d, base, vals = _rand_state(rng, 2, g.num_blocks, 16, semiring)
         v, dl = fused_push(vals if vals is not None
@@ -307,25 +327,25 @@ def test_push_shared_drops_sentinel_neighbors_like_vmap(semiring):
 
 @pytest.mark.parametrize("semiring", ["plus_times", "min_plus"])
 def test_prime_job_count_degrades_chunk_and_validates(monkeypatch, semiring):
-    """J=13 (prime) under a tight VMEM budget: the only divisor under the
-    cap is 1 — the kernel must still validate and its per-cell footprint
-    must honour the (monkeypatched) budget."""
+    """Job chunks must be J or a multiple of 8 (Mosaic tiles a block's
+    last two dims).  Under a tight VMEM budget J=24 degrades to the
+    8-job chunk, within budget, and validates; a prime J=13 has no legal
+    chunk but the whole axis."""
     from repro.analysis import contracts as C
+    from repro.kernels.fused_superstep.ops import block_vmem_bytes
     vb = 16
-    stripes = 3 if semiring == "plus_times" else 6
-    fixed = vb * vb * 4
-    per_job = (stripes * vb + 2) * 4
-    budget = fixed + 4 * per_job + 1        # room for jb=4 -> degrade to 1
+    budget = block_vmem_bytes(12, vb, semiring)   # room for 12, not 24
     monkeypatch.setattr(common, "VMEM_BUDGET", budget)
-    assert _pick_job_block(13, vb, semiring) == 1
-    assert C.fused_superstep_vmem_bytes(13, vb, semiring) <= budget
+    assert _pick_job_block(24, vb, semiring) == 8
+    assert C.fused_superstep_vmem_bytes(24, vb, semiring) <= budget
+    assert _pick_job_block(13, vb, semiring) == 13
 
     g, bp = _pairs_for(semiring, vb=vb)
     rng = np.random.default_rng(9)
-    d, base, vals = _rand_state(rng, 13, g.num_blocks, vb, semiring)
+    d, base, vals = _rand_state(rng, 24, g.num_blocks, vb, semiring)
     sel = jnp.asarray([0, 2, 5], jnp.int32)
     msk = jnp.ones(3, jnp.float32)
-    scales = jnp.ones(13, jnp.float32)
+    scales = jnp.ones(24, jnp.float32)
     push_one = push_plus_one if semiring == "plus_times" else push_min_one
     if vals is None:
         vals = jnp.zeros_like(base)
