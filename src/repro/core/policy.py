@@ -58,7 +58,6 @@ creation order (`GraphSession.job_index(handle)` maps a handle to its row;
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import time
@@ -75,6 +74,7 @@ from repro.core.push import compute_pairs, indep_push_fn, shared_push_fn
 from repro.obs.telemetry import (HostSeriesBuilder, TelemetrySeries,
                                  device_buffers, device_write,
                                  series_from_device)
+from repro.obs.trace import count, span
 
 HOST, DEVICE = "host", "device"
 
@@ -89,6 +89,10 @@ class RunMetrics:
     # block once across views regardless of how many of its K ELL slots
     # are padding
     tile_pair_loads: int = 0
+    # block-pair tiles the pushes read, summed over executed supersteps
+    # (`pairs_streamed_per_push`): what the push streams, against the
+    # pairs the schedule selected in tile_pair_loads; 0 on a 2D mesh
+    pairs_streamed: int = 0
     job_block_pushes: int = 0      # (job, block) processing events
     host_syncs: int = 0            # scheduling host<->device round-trips
     # cross-shard frontier payload of a 2D (jobs x blocks) mesh run
@@ -115,6 +119,7 @@ class RunMetrics:
         d = {"supersteps": int(self.supersteps),
              "tile_loads": int(self.tile_loads),
              "tile_pair_loads": int(self.tile_pair_loads),
+             "pairs_streamed": int(self.pairs_streamed),
              "job_block_pushes": int(self.job_block_pushes),
              "host_syncs": int(self.host_syncs),
              "halo_bytes": float(self.halo_bytes),
@@ -206,16 +211,31 @@ class SchedulePolicy:
         else:
             m = _run_host(self, sess, max_supersteps)
         m.wall_time_s = time.perf_counter() - t0
+        count("pairs_streamed", m.pairs_streamed)
         return m
 
 
-def _profiler_span(sess, name: str):
-    """jax.profiler annotation for one scheduling dispatch, opt-in via
-    TelemetryConfig(jax_profiler=True); a no-op context otherwise."""
-    cfg = getattr(sess, "telemetry", None)
-    if cfg is not None and cfg.jax_profiler:
-        return jax.profiler.TraceAnnotation(name)
-    return contextlib.nullcontext()
+def pairs_streamed_per_push(sess, grp, shared: bool) -> int:
+    """Block-pair tiles one push of view group `grp` reads, fixed where
+    the push is built (`core.push.shared_push_fn` / `indep_push_fn`):
+
+      fused kernel (use_pallas, shared)   its grid (J/Jb, P) over the
+                                          PAIR_CHUNK chain: every pair for
+                                          each job chunk, selected or not
+      jnp pair sweep (plus-times, shared) every pair once: P
+      block-ELL push (min-plus jnp shared, every independent push)
+                                          the q selection slots' K ELL
+                                          tiles, once (shared) or per job
+    """
+    if shared and sess.use_pallas:
+        from repro.kernels.fused_superstep.ops import _pick_job_block
+        j, _, vb = grp.values.shape
+        jb = _pick_job_block(j, vb, grp.semiring)
+        return j // jb * sess._pair_data(grp).num_pairs
+    if shared and grp.semiring == "plus_times":
+        return sess._pair_data(grp).num_pairs
+    ell = sess.q * grp.graph.tiles.shape[1]
+    return ell if shared else grp.capacity * ell
 
 
 # ---------------------------------------------------------------------------
@@ -257,6 +277,7 @@ def _run_host(policy: SchedulePolicy, sess,
     # device_get: the driver may run under the transfer sentinel)
     nnz_host = [np.asarray(x) for x in
                 jax.device_get([p.src_nnz for p in grp_pairs])]
+    count("device_reads", 1)
     m = RunMetrics(
         iterations_per_job=np.zeros(int(offs[-1]), dtype=np.int64))
     telemetry = getattr(sess, "telemetry", None) is not None
@@ -293,7 +314,7 @@ def _run_host(policy: SchedulePolicy, sess,
         dirty_n = int((boost > 0).sum()) if boost is not None else 0
         actives = []
         node_un = p_mean = None
-        with _profiler_span(sess, "superstep.schedule"):
+        with span("session.run.schedule"):
             if policy.needs_pairs:
                 node_un, p_mean = [], []
                 for gi, g in enumerate(groups):
@@ -312,6 +333,7 @@ def _run_host(policy: SchedulePolicy, sess,
                         resids[gi] = float(rs)
                     else:
                         nu, pm = jax.device_get(out)
+                    count("device_reads", 1)
                     if boost is not None:
                         pm = pm + boost[None, :] * (nu > 0)
                     node_un.append(nu)
@@ -334,6 +356,7 @@ def _run_host(policy: SchedulePolicy, sess,
                         resids[gi] = float(rs)
                     else:
                         counts = jax.device_get(out)
+                    count("device_reads", 1)
                     node_un.append(counts)
                     actives.append(counts > 0)
                     if not actives[gi].any():
@@ -354,7 +377,7 @@ def _run_host(policy: SchedulePolicy, sess,
         # session, which stops outright; for plus-times this also keeps
         # sub-tolerance residual mass where convergence left it)
         pair_step = 0
-        with _profiler_span(sess, "superstep.push"):
+        with span("session.run.push"):
             if selection.shared:
                 sel = jnp.asarray(selection.sel)
                 msk = jnp.asarray(selection.msk)
@@ -364,6 +387,9 @@ def _run_host(policy: SchedulePolicy, sess,
                     if not actives[gi].any():
                         continue
                     pair_step += int(nnz_host[gi][sel_np][on_np].sum())
+                    if mesh2d is None:
+                        m.pairs_streamed += pairs_streamed_per_push(
+                            sess, g, True)
                     g.values, g.deltas = sess._push_shared_fn(g)(
                         g.values, g.deltas, g.graph.tiles, g.graph.nbr_ids,
                         sel, msk, g.push_scale, g.overlay, grp_pairs[gi])
@@ -374,6 +400,9 @@ def _run_host(policy: SchedulePolicy, sess,
                     sel_np = np.asarray(selection.sel[gi])
                     on_np = np.asarray(selection.msk[gi]) > 0
                     pair_step += int((nnz_host[gi][sel_np] * on_np).sum())
+                    if mesh2d is None:
+                        m.pairs_streamed += pairs_streamed_per_push(
+                            sess, g, False)
                     args = (g.values, g.deltas, g.graph.tiles,
                             g.graph.nbr_ids,
                             jnp.asarray(selection.sel[gi]),
@@ -616,7 +645,6 @@ def _run_device(policy: SchedulePolicy, sess,
     tel_cfg = getattr(sess, "telemetry", None)
     tel_cap = int(tel_cfg.capacity) if tel_cfg is not None else 0
     trace = getattr(sess, "trace", None)
-    trace = trace if trace is not None and trace.enabled else None
     # the budget the device compares against must be the SAME clamped
     # value the host loop tests, or a >int32 budget could spin forever
     budget = int(min(max_supersteps, np.iinfo(np.int32).max))
@@ -624,26 +652,32 @@ def _run_device(policy: SchedulePolicy, sess,
         device_step_args(sess, budget, sess._consume_dirty_boost())
     m = RunMetrics()
     while True:
-        t_chunk = trace.now_us() if trace else 0.0
-        with _profiler_span(sess, "device_chunk"):
+        with span("session.run.chunk", trace, cat="superstep", tid=2,
+                  sync=m.host_syncs) as chunk:
             state, un = step_fn(state, scales, tiles, nbrs, ovs, prs,
                                 max_steps, key)
             # the ONE host sync of the chunk: explicit, batched, and the
             # only transfer a transfer_guard("disallow") run will see
-            it_h, un_h = map(int, jax.device_get((state[0], un)))
+            with span("session.run.chunk.wait"):
+                it_h, un_h = map(int, jax.device_get((state[0], un)))
+            count("device_reads", 1)
+            chunk.set(supersteps_done=it_h)
         m.host_syncs += 1
-        if trace:
-            trace.complete("device_chunk", t_chunk,
-                           trace.now_us() - t_chunk, cat="superstep", tid=2,
-                           sync=m.host_syncs - 1, supersteps_done=it_h)
         if un_h == 0 or it_h >= budget:
             break
     sess.scheduler._step += it_h
     for gi, g in enumerate(groups):
         g.values, g.deltas = state[1][gi], state[2][gi]
     m.supersteps = it_h
-    loads_h, pushes_h, pair_loads_h, iters_h = jax.device_get(
-        (state[3], state[4], state[5], state[6]))
+    # every executed superstep pushes every group (a converged group's
+    # push is computed, then discarded)
+    shared = not isinstance(policy, Independent)
+    m.pairs_streamed = it_h * sum(pairs_streamed_per_push(sess, g, shared)
+                                  for g in groups)
+    with span("session.run.readout"):
+        loads_h, pushes_h, pair_loads_h, iters_h = jax.device_get(
+            (state[3], state[4], state[5], state[6]))
+    count("device_reads", 1)
     m.tile_loads = int(loads_h)
     m.job_block_pushes = int(pushes_h)
     m.tile_pair_loads = int(pair_loads_h)

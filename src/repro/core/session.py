@@ -55,7 +55,7 @@ from repro.core.global_q import DEFAULT_ALPHA
 from repro.graph.structure import (BlockedGraph, CSRGraph, TileOverlay,
                                    build_blocked, empty_overlay)
 from repro.obs.telemetry import TelemetryConfig
-from repro.obs.trace import TraceRecorder
+from repro.obs.trace import TraceRecorder, count, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -358,20 +358,24 @@ class GraphSession:
         """Admit a job at any superstep; recycles a free slot or grows its
         view group.  Jobs of a NEW graph view build that view lazily from
         the shared CSR and coexist with every already-running family."""
-        grp = self._group_for(alg)
-        free = np.nonzero(~grp.active)[0]
-        if len(free) == 0:
-            self._grow(grp)
+        with span("session.submit", self.trace, cat="job",
+                  alg=type(alg).__name__) as s:
+            grp = self._group_for(alg)
             free = np.nonzero(~grp.active)[0]
-        slot = int(free[0])
-        v, d = alg.init(grp.graph)
-        grp.values = grp.values.at[slot].set(v)
-        grp.deltas = grp.deltas.at[slot].set(d)
-        grp.push_scale = grp.push_scale.at[slot].set(alg.get_push_scale())
-        grp.algs[slot] = alg
-        grp.active[slot] = True
-        self.trace.instant("submit", cat="job", alg=type(alg).__name__,
-                           view=str(grp.key), slot=slot)
+            if len(free) == 0:
+                self._grow(grp)
+                free = np.nonzero(~grp.active)[0]
+            slot = int(free[0])
+            s.set(view=grp.key, slot=slot, gen=grp.gens[slot])
+            with span("session.submit.init"):
+                v, d = alg.init(grp.graph)
+            with span("session.submit.write"):
+                grp.values = grp.values.at[slot].set(v)
+                grp.deltas = grp.deltas.at[slot].set(d)
+                grp.push_scale = grp.push_scale.at[slot].set(
+                    alg.get_push_scale())
+            grp.algs[slot] = alg
+            grp.active[slot] = True
         return JobHandle(slot=slot, gen=grp.gens[slot], alg=alg, view=grp.key)
 
     def _handle_group(self, handle: JobHandle) -> ViewGroup:
@@ -401,14 +405,19 @@ class GraphSession:
         concatenated in creation order (0 for free slots) — one device
         reduction per view; index by `job_index(handle)` to poll many
         handles (== handle.slot for single-view sessions)."""
-        parts = [jax.device_get(self._counts_fn(g)(g.values, g.deltas))
-                 for g in self.groups.values()]
+        with span("session.poll"):
+            parts = []
+            for g in self.groups.values():
+                parts.append(jax.device_get(
+                    self._counts_fn(g)(g.values, g.deltas)))
+                count("device_reads", 1)
         return (np.concatenate(parts) if parts
                 else np.zeros(0, dtype=np.int32))
 
     def converged(self, handle: JobHandle) -> bool:
         grp = self._handle_group(handle)
         counts = jax.device_get(self._counts_fn(grp)(grp.values, grp.deltas))
+        count("device_reads", 1)
         return bool(counts[handle.slot] == 0)
 
     def result(self, handle: JobHandle) -> np.ndarray:
@@ -416,23 +425,27 @@ class GraphSession:
         grp = self._handle_group(handle)
         res = handle.alg.result(grp.values[handle.slot],
                                 grp.deltas[handle.slot])
-        return jax.device_get(res).reshape(-1)[:grp.graph.n_real]
+        out = jax.device_get(res)
+        count("device_reads", 1)
+        return out.reshape(-1)[:grp.graph.n_real]
 
     def detach(self, handle: JobHandle) -> np.ndarray:
         """Extract the job's result and free its slot for reuse."""
-        res = self.result(handle)
-        grp = self._handle_group(handle)
-        slot = handle.slot
-        iv, idl = _inert_state(grp.semiring, grp.graph, 1)
-        grp.values = grp.values.at[slot].set(iv[0])
-        grp.deltas = grp.deltas.at[slot].set(idl[0])
-        grp.push_scale = grp.push_scale.at[slot].set(1.0)
-        grp.algs[slot] = None
-        grp.active[slot] = False
-        grp.gens[slot] += 1
-        self.trace.instant("detach", cat="job",
-                           alg=type(handle.alg).__name__,
-                           view=str(grp.key), slot=slot)
+        with span("session.detach", self.trace, cat="job",
+                  alg=type(handle.alg).__name__, view=handle.view,
+                  slot=handle.slot, gen=handle.gen):
+            with span("session.detach.read"):
+                res = self.result(handle)
+            grp = self._handle_group(handle)
+            slot = handle.slot
+            with span("session.detach.reset"):
+                iv, idl = _inert_state(grp.semiring, grp.graph, 1)
+                grp.values = grp.values.at[slot].set(iv[0])
+                grp.deltas = grp.deltas.at[slot].set(idl[0])
+                grp.push_scale = grp.push_scale.at[slot].set(1.0)
+            grp.algs[slot] = None
+            grp.active[slot] = False
+            grp.gens[slot] += 1
         return res
 
     # -- evolving graphs (repro.stream) --------------------------------------
@@ -656,18 +669,19 @@ class GraphSession:
             raise ValueError("no jobs submitted yet")
         policy = TwoLevel() if policy is None else policy
         self._place(mesh)
-        t_run = self.trace.now_us() if self.trace.enabled else 0.0
-        m = policy.run(self, max_supersteps)
-        self._drain_stream_stats(m)
+        with span("session.run", self.trace, cat="run",
+                  policy=policy.name) as s:
+            t_run = self.trace.now_us() if self.trace.enabled else 0.0
+            m = policy.run(self, max_supersteps)
+            self._drain_stream_stats(m)
+            if self.trace.enabled:
+                s.set(**m.to_dict())
         if self.trace.enabled:
-            self._trace_run(policy, m, t_run)
+            self._trace_run(m, t_run, self.trace.now_us() - t_run)
         return m
 
-    def _trace_run(self, policy, m: RunMetrics, t_run: float) -> None:
-        """One run() span + counter tracks from the telemetry series."""
-        dur = self.trace.now_us() - t_run
-        self.trace.complete("run", t_run, dur, cat="run",
-                            policy=policy.name, **m.to_dict())
+    def _trace_run(self, m: RunMetrics, t_run: float, dur: float) -> None:
+        """Counter tracks from the telemetry series, across the run span."""
         if m.converged:
             self.trace.instant("converged", cat="run",
                                supersteps=int(m.supersteps))

@@ -64,6 +64,7 @@ from repro.core.global_q import accumulate_priority, synthesize_topq
 from repro.core.push import _block_mask
 from repro.dist.compression import quantize_ef
 from repro.obs.telemetry import device_buffers, device_write
+from repro.obs.trace import count, span
 
 JOBS_AXIS, BLOCKS_AXIS = "jobs", "blocks"
 
@@ -842,7 +843,6 @@ def run_device_2d(policy, sess, max_supersteps: int):
     tel_cfg = getattr(sess, "telemetry", None)
     tel_cap = int(tel_cfg.capacity) if tel_cfg is not None else 0
     trace = getattr(sess, "trace", None)
-    trace = trace if trace is not None and trace.enabled else None
     compress = [spec.compress_halo and g.semiring == PLUS_TIMES
                 and not _policy_is_indep(policy) and lay.blocks_sharded
                 for g, lay in zip(groups, lays)]
@@ -870,23 +870,25 @@ def run_device_2d(policy, sess, max_supersteps: int):
                              sess.scheduler._step)
     m = RunMetrics()
     while True:
-        t_chunk = trace.now_us() if trace else 0.0
-        state, un = step_fn(state, scales, tiles, nbrs, ovs, prs,
-                            max_steps, key)
-        it_h, un_h = map(int, jax.device_get((state[0], un)))
+        with span("session.run.chunk", trace, cat="superstep", tid=2,
+                  sync=m.host_syncs) as chunk:
+            state, un = step_fn(state, scales, tiles, nbrs, ovs, prs,
+                                max_steps, key)
+            with span("session.run.chunk.wait"):
+                it_h, un_h = map(int, jax.device_get((state[0], un)))
+            count("device_reads", 1)
+            chunk.set(supersteps_done=it_h)
         m.host_syncs += 1
-        if trace:
-            trace.complete("device_chunk", t_chunk,
-                           trace.now_us() - t_chunk, cat="superstep", tid=2,
-                           sync=m.host_syncs - 1, supersteps_done=it_h)
         if un_h == 0 or it_h >= budget:
             break
     sess.scheduler._step += it_h
     for gi, g in enumerate(groups):
         g.values, g.deltas = state[1][gi], state[2][gi]
     m.supersteps = it_h
-    loads_h, pushes_h, pair_loads_h, iters_h, halo_h = jax.device_get(
-        (state[3], state[4], state[5], state[6], state[9]))
+    with span("session.run.readout"):
+        loads_h, pushes_h, pair_loads_h, iters_h, halo_h = jax.device_get(
+            (state[3], state[4], state[5], state[6], state[9]))
+    count("device_reads", 1)
     m.tile_loads = int(loads_h)
     m.job_block_pushes = int(pushes_h)
     m.tile_pair_loads = int(pair_loads_h)

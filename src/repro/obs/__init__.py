@@ -10,7 +10,11 @@ Three parts, one opt-in switch:
   trace      - every session owns a ``TraceRecorder`` (``session.trace``)
                collecting submit/detach, superstep spans, apply_updates
                batches and compactions; ``session.trace.export(path)``
-               writes Chrome/Perfetto trace-event JSON.
+               writes Chrome/Perfetto trace-event JSON.  Whatever the
+               switch, the program's spans (``trace.span``) are written to
+               a live JAX profiler trace as ``repro.<name>`` annotations
+               and summed, with its counters (``trace.count``), in
+               ``trace.digest()``.
   serve      - ``ConcurrentServeScheduler.metrics`` records per-stream
                wait/service time and per-family queue depth with p50/p99
                summaries (the SLO signal of ROADMAP item 3).

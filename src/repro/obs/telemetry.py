@@ -69,15 +69,13 @@ class TelemetryConfig:
     trace         record structured trace events on ``session.trace``
                   (submit/detach, superstep spans, apply_updates batches,
                   compactions) for Chrome/Perfetto export
-    jax_profiler  additionally wrap scheduling dispatches in
-                  jax.profiler.TraceAnnotation spans (visible in a
-                  jax.profiler trace; off by default — it is only useful
-                  under an active profiler session)
+
+    The program's spans reach a live JAX profiler trace whatever this
+    says (``repro.obs.trace.span``).
     """
 
     capacity: int = DEFAULT_CAPACITY
     trace: bool = True
-    jax_profiler: bool = False
 
     @staticmethod
     def coerce(value: Union[None, bool, "TelemetryConfig"]

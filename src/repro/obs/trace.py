@@ -1,4 +1,25 @@
-"""Structured trace events, exported as Chrome/Perfetto trace-event JSON.
+"""Program spans and counters: the JAX profiler's host trace, a digest of
+the profiled interval, and Chrome/Perfetto trace-event JSON.
+
+``span(name, recorder=None, **args)`` is the program's one span path:
+
+  profiler  while a JAX profiler trace is live
+            (``jax.profiler.TraceAnnotation.is_enabled()``), the span is a
+            ``TraceAnnotation`` named ``repro.<name>`` carrying ``args``:
+            it lands on the host plane of the ``.xplane.pb``, on the same
+            clock as the device's ops, nested in whatever annotation
+            encloses it.
+  digest    while a profiler trace is live, each span also adds to a
+            module-level digest: per name its count, total seconds, self
+            seconds (total less the time of the spans nested in it on the
+            same thread) and longest seconds; ``count(name, n)`` adds to a
+            counter there.  ``digest()`` returns a copy, ``reset_digest()``
+            clears it.  With no profiler live, neither a span nor a
+            counter records anything (one flag read), so the digest covers
+            exactly the profiled interval.
+  recorder  an enabled ``TraceRecorder`` (a session built with
+            ``telemetry=``) also gets a complete event (ph="X") on its own
+            clock.
 
 A ``TraceRecorder`` collects the discrete story of a session — job
 submit/detach, run and superstep spans, apply_updates batches, overlay
@@ -14,24 +35,121 @@ compactions, serve admissions — as Trace Event Format records
 chrome://tracing and https://ui.perfetto.dev as-is.  Timestamps are
 microseconds on a perf_counter clock anchored at recorder creation.
 
-Recording is cheap (an appended dict per event) but still gated on
-``enabled`` so telemetry-off sessions pay literally nothing; a disabled
-recorder's export writes an empty-but-valid trace.
+Recording is gated on ``enabled`` so telemetry-off sessions pay nothing
+for it; a disabled recorder's export writes an empty-but-valid trace.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
+import threading
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["TraceRecorder", "validate_trace_events"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["TraceRecorder", "validate_trace_events", "span", "count",
+           "digest", "reset_digest"]
 
 # phases this recorder emits (export-time schema guarantee)
 _PHASES = ("X", "i", "C", "M")
 
 REQUIRED_KEYS = ("name", "ph", "ts", "pid", "tid")
+
+_live = TraceAnnotation.is_enabled
+
+# the digest of the profiled interval: per span name [count, total_s,
+# self_s, max_s]; per counter its sum
+_spans: Dict[str, list] = {}
+_counters: Dict[str, float] = {}
+# per thread, the child seconds of each open digest span, innermost last
+_stack = threading.local()
+_digest_lock = threading.Lock()
+
+
+class _Span:
+    """What `span` returns; `set(**args)` adds args known only inside."""
+
+    __slots__ = ("name", "recorder", "cat", "tid", "args", "_ann", "_t0",
+                 "_us0", "_kids")
+
+    def __init__(self, name, recorder, cat, tid, args):
+        self.name, self.recorder, self.cat, self.tid, self.args = (
+            name, recorder, cat, tid, args)
+        self._ann = self._us0 = None
+
+    def __enter__(self):
+        rec = self.recorder
+        if rec is not None and rec.enabled:
+            self._us0 = rec.now_us()
+        if _live():
+            self._ann = TraceAnnotation(f"repro.{self.name}", **self.args)
+            self._ann.__enter__()
+            kids = getattr(_stack, "kids", None)
+            if kids is None:
+                kids = _stack.kids = []
+            kids.append(0.0)
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            dur = time.perf_counter() - self._t0
+            self._ann.__exit__(*exc)
+            kids = _stack.kids
+            own = dur - kids.pop()
+            if kids:
+                kids[-1] += dur
+            with _digest_lock:
+                d = _spans.get(self.name)
+                if d is None:
+                    _spans[self.name] = [1, dur, own, dur]
+                else:
+                    d[0] += 1
+                    d[1] += dur
+                    d[2] += own
+                    d[3] = max(d[3], dur)
+        if self._us0 is not None:
+            self.recorder.complete(self.name, self._us0,
+                                   self.recorder.now_us() - self._us0,
+                                   cat=self.cat, tid=self.tid, **self.args)
+        return False
+
+
+def span(name: str, recorder: Optional["TraceRecorder"] = None, *,
+         cat: str = "session", tid: int = 1, **args) -> _Span:
+    """Context manager around one piece of program work (module
+    docstring); `cat` and `tid` place it on the recorder's tracks."""
+    return _Span(name, recorder, cat, tid, args)
+
+
+def count(name: str, n: float) -> None:
+    """Add `n` to the digest's counter `name` while a profiler trace is
+    live."""
+    if _live():
+        with _digest_lock:
+            _counters[name] = _counters.get(name, 0) + n
+
+
+def digest() -> dict:
+    """A copy of the digest: {"spans": {name: {"count", "total_s",
+    "self_s", "max_s"}}, "counters": {name: sum}}."""
+    with _digest_lock:
+        return {"spans": {k: dict(zip(("count", "total_s", "self_s",
+                                       "max_s"), v))
+                          for k, v in _spans.items()},
+                "counters": dict(_counters)}
+
+
+def reset_digest() -> None:
+    with _digest_lock:
+        _spans.clear()
+        _counters.clear()
 
 
 class TraceRecorder:
@@ -73,18 +191,11 @@ class TraceRecorder:
                     "dur": max(dur_us, 0.0), "pid": self.pid, "tid": tid,
                     "args": args})
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "session", tid: int = 1, **args):
-        """Context manager emitting one complete span around the body."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.now_us() - t0, cat=cat, tid=tid,
-                          **args)
+    def span(self, name: str, cat: str = "session", tid: int = 1,
+             **args) -> _Span:
+        """`span(name, recorder=self, ...)`: one complete event around the
+        body, and the profiler annotation while a trace is live."""
+        return span(name, self, cat=cat, tid=tid, **args)
 
     def counter(self, name: str, values: Dict[str, float],
                 ts_us: Optional[float] = None, cat: str = "telemetry",

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core.scheduler import TwoLevelScheduler
 from repro.obs.serve import ServeMetrics
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -143,6 +144,10 @@ class ConcurrentServeScheduler:
     def schedule_step(self) -> List[Request]:
         """Pick request groups via the two-level policy, then admit requests
         from selected groups (all streams share them — CAJS) up to budget."""
+        with span("serve.schedule"):
+            return self._schedule_step()
+
+    def _schedule_step(self) -> List[Request]:
         streams = [self.streams[sid] for sid in sorted(self.streams)]
         step = self._step_idx
         if self.metrics is not None or self.slo is not None:

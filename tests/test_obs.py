@@ -273,8 +273,18 @@ def test_trace_export_is_valid_chrome_trace_json(tmp_path):
     doc = json.loads(path.read_text())
     assert validate_trace_events(doc) == len(doc["traceEvents"])
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"submit", "detach", "run", "superstep", "apply_updates",
-            "converged", "process_name"} <= names
+    assert {"session.submit", "session.detach", "session.run", "superstep",
+            "apply_updates", "converged", "process_name"} <= names
+    # submit and detach are spans with a duration; (view, slot, gen)
+    # joins the PPR job's two
+    jobs = [e for e in doc["traceEvents"]
+            if e["name"] in ("session.submit", "session.detach")]
+    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in jobs)
+    key = (h.view, h.slot, h.gen)
+    assert [e["name"] for e in jobs
+            if (tuple(e["args"]["view"]), e["args"]["slot"],
+                e["args"]["gen"]) == key] == ["session.submit",
+                                              "session.detach"]
     # per-superstep spans landed on the named superstep track
     spans = [e for e in doc["traceEvents"] if e["name"] == "superstep"]
     assert spans and all(e["ph"] == "X" and e["dur"] >= 0 for e in spans)
@@ -300,7 +310,8 @@ def test_trace_schema_validator_rejects_malformed_events():
 def test_device_chunks_traced_per_sync():
     sess = _session()
     m = sess.run(TwoLevel(backend="device", steps_per_sync=8), 500)
-    chunks = [e for e in sess.trace.events if e["name"] == "device_chunk"]
+    chunks = [e for e in sess.trace.events
+              if e["name"] == "session.run.chunk"]
     assert len(chunks) == m.host_syncs
 
 
