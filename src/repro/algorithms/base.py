@@ -21,7 +21,7 @@ use the monotone transform 1/(1+dist)).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import ClassVar, Tuple
 
 import jax.numpy as jnp
 
@@ -40,6 +40,13 @@ class Algorithm:
     name: str = "abstract"
     semiring: str = PLUS_TIMES
     tolerance: float = 1e-6     # |delta| < tol  ==> vertex converged (plus-times)
+
+    #: the fields that differ from job to job (a query's source).  A
+    #: session admits and retires a job with one compiled program per
+    #: class and static fields, which takes these as traced arguments: so
+    #: `init` and `result` must trace with them (and with the graph's
+    #: arrays) as arrays.
+    job_fields: ClassVar[Tuple[str, ...]] = ()
 
     def get_push_scale(self) -> float:
         """Multiplies deltas before the push (PageRank damping, Katz alpha)."""

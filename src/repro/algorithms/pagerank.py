@@ -10,7 +10,7 @@ and the pushed delta folds into values.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import ClassVar, Tuple
 
 import jax.numpy as jnp
 
@@ -43,6 +43,7 @@ class PersonalizedPageRank(Algorithm):
     semiring: str = PLUS_TIMES
     damping: float = 0.85
     source: int = 0
+    job_fields: ClassVar[Tuple[str, ...]] = ("source",)
     tolerance: float = 1e-7
     graph_normalize: str | None = "out_degree"
 

@@ -7,7 +7,7 @@ distance (finite only where the vertex improved since it was last pushed).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import ClassVar, Tuple
 
 import jax.numpy as jnp
 
@@ -20,6 +20,7 @@ class SSSP(Algorithm):
     name: str = "sssp"
     semiring: str = MIN_PLUS
     source: int = 0
+    job_fields: ClassVar[Tuple[str, ...]] = ("source",)
     graph_fill: float = float("inf")
     graph_normalize: str | None = None
 

@@ -126,12 +126,75 @@ class ViewGroup:
         return int(self.active.sum())
 
 
+def _inert_fill(semiring: str) -> float:
+    return 0.0 if semiring == PLUS_TIMES else float("inf")
+
+
 def _inert_state(semiring: str, g: BlockedGraph, n: int):
     """State for free slots: converged-everywhere, pushes are no-ops."""
-    fill = 0.0 if semiring == PLUS_TIMES else jnp.inf
+    fill = _inert_fill(semiring)
     shape = (n, g.num_blocks, g.block_size)
     return (jnp.full(shape, fill, dtype=jnp.float32),
             jnp.full(shape, fill, dtype=jnp.float32))
+
+
+# -- slot programs: a job's admission and retirement, one dispatch each ------
+#
+# Each is compiled once per (view, algorithm class and static fields): the
+# slot and the job's own numbers (`Algorithm.job_fields`) are traced
+# arguments, so every slot and every source shares one compilation.
+
+def _job_args(alg: Algorithm) -> tuple:
+    """The job's own numbers as arguments of its slot programs: numpy
+    values, so every job of a class traces to the same signature."""
+    return tuple(np.asarray(getattr(alg, f)) for f in alg.job_fields)
+
+
+def _with_job(alg: Algorithm, job: tuple) -> Algorithm:
+    return dataclasses.replace(alg, **dict(zip(alg.job_fields, job)))
+
+
+def _admit_program(alg: Algorithm):
+    """The job's state from its own `init`, and its push scale, written
+    into `slot` of the view's job axis."""
+    def admit(values, deltas, push_scale, slot, job, graph):
+        a = _with_job(alg, job)
+        v, d = a.init(graph)
+        return (values.at[slot].set(v), deltas.at[slot].set(d),
+                push_scale.at[slot].set(a.get_push_scale()))
+    return jax.jit(admit)
+
+
+def _retire_program(alg: Algorithm, fill: float):
+    """The job's result, with `slot` reset to the inert state."""
+    def retire(values, deltas, push_scale, slot, job):
+        res = _with_job(alg, job).result(values[slot], deltas[slot])
+        return (res, values.at[slot].set(fill), deltas.at[slot].set(fill),
+                push_scale.at[slot].set(1.0))
+    return jax.jit(retire)
+
+
+def _dispatch(program, *args):
+    """One call of a slot program; counts it, and each compilation it
+    caused (a new program, shape or placement)."""
+    n = program._cache_size()
+    out = program(*args)
+    count("slot_programs", 1)
+    count("slot_compiles", program._cache_size() - n)
+    return out
+
+
+def _store(grp: ViewGroup, values, deltas, push_scale) -> None:
+    """A slot program's state into `grp`, on the placement the group's
+    state had: the compiled program may pick an equivalent sharding of its
+    own (a size-1 mesh axis dropped), which the next superstep would
+    compile for again."""
+    def placed(new, old):
+        return (new if new.sharding == old.sharding
+                else jax.device_put(new, old.sharding))
+    grp.values = placed(values, grp.values)
+    grp.deltas = placed(deltas, grp.deltas)
+    grp.push_scale = placed(push_scale, grp.push_scale)
 
 
 class GraphSession:
@@ -367,13 +430,11 @@ class GraphSession:
                 free = np.nonzero(~grp.active)[0]
             slot = int(free[0])
             s.set(view=grp.key, slot=slot, gen=grp.gens[slot])
-            with span("session.submit.init"):
-                v, d = alg.init(grp.graph)
-            with span("session.submit.write"):
-                grp.values = grp.values.at[slot].set(v)
-                grp.deltas = grp.deltas.at[slot].set(d)
-                grp.push_scale = grp.push_scale.at[slot].set(
-                    alg.get_push_scale())
+            admit = self._slot_program("admit", grp, alg)
+            with span("session.submit.program"):
+                _store(grp, *_dispatch(
+                    admit, grp.values, grp.deltas, grp.push_scale,
+                    np.int32(slot), _job_args(alg), grp.graph))
             grp.algs[slot] = alg
             grp.active[slot] = True
         return JobHandle(slot=slot, gen=grp.gens[slot], alg=alg, view=grp.key)
@@ -434,19 +495,21 @@ class GraphSession:
         with span("session.detach", self.trace, cat="job",
                   alg=type(handle.alg).__name__, view=handle.view,
                   slot=handle.slot, gen=handle.gen):
-            with span("session.detach.read"):
-                res = self.result(handle)
             grp = self._handle_group(handle)
             slot = handle.slot
-            with span("session.detach.reset"):
-                iv, idl = _inert_state(grp.semiring, grp.graph, 1)
-                grp.values = grp.values.at[slot].set(iv[0])
-                grp.deltas = grp.deltas.at[slot].set(idl[0])
-                grp.push_scale = grp.push_scale.at[slot].set(1.0)
+            retire = self._slot_program("retire", grp, handle.alg)
+            with span("session.detach.program"):
+                res, *state = _dispatch(
+                    retire, grp.values, grp.deltas, grp.push_scale,
+                    np.int32(slot), _job_args(handle.alg))
+                _store(grp, *state)
+            with span("session.detach.read"):
+                out = jax.device_get(res)
+            count("device_reads", 1)
             grp.algs[slot] = None
             grp.active[slot] = False
             grp.gens[slot] += 1
-        return res
+        return out.reshape(-1)[:grp.graph.n_real]
 
     # -- evolving graphs (repro.stream) --------------------------------------
 
@@ -543,6 +606,17 @@ class GraphSession:
             return self._jit_cache[key]
         if key not in self._jit_cache:
             self._jit_cache[key] = build_device_step(policy, self)
+        return self._jit_cache[key]
+
+    def _slot_program(self, kind: str, grp: ViewGroup, alg: Algorithm):
+        """The view's "admit" or "retire" program for `alg`'s class and
+        static fields (its job fields cleared), built on first use."""
+        static = dataclasses.replace(alg, **dict.fromkeys(alg.job_fields))
+        key = (kind, grp.key, static)
+        if key not in self._jit_cache:
+            self._jit_cache[key] = (
+                _admit_program(static) if kind == "admit"
+                else _retire_program(static, _inert_fill(grp.semiring)))
         return self._jit_cache[key]
 
     def _pairs_fn(self, grp: ViewGroup, with_resid: bool = False):

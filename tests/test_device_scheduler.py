@@ -257,6 +257,11 @@ def test_superstep_compiles_once_across_runs_and_resubmissions(
     whole scenario runs under the transfer sentinel (every sync must be
     an explicit device_get) and runs 2-3 under the retrace sentinel."""
     sess = GraphSession(CSR, 32, capacity=2, seed=5)
+    # submit / detach compile one program per (view, class, static
+    # fields) on first use: warm the ones the pinned block calls, so
+    # that it compiles nothing at all
+    for alg in (PageRank(), PageRank(damping=0.6)):
+        sess.detach(sess.submit(alg))
     h0 = sess.submit(PageRank())
     assert sess.run(Fused(), 20000).converged
     sess.submit(PersonalizedPageRank(source=7))     # same capacity

@@ -5,8 +5,9 @@ clock, and the digest of the profiled interval.
     digest, and the recorder's events are what they are with one live;
   * under `jax.profiler.start_trace`, a session's submit / run / poll /
     detach and the serve front's schedule_step give digest counts equal
-    to the calls, self time <= total, and one `device_reads` per
-    blocking read;
+    to the calls, self time <= total, one `device_reads` per blocking
+    read, one `slot_programs` per submit or detach and no
+    `slot_compiles` once the programs exist;
   * the `repro.*` annotations sit on the xplane's host plane, each child
     inside its parent and all inside an enclosing annotation;
   * `pairs_streamed` is the fused kernel's grid pair steps per superstep,
@@ -87,7 +88,7 @@ def test_no_profiler_no_digest_and_the_recorder_unchanged(tmp_path):
     assert names.count("session.detach") == 3
     assert "session.run" in names and "session.run.chunk" in names
     # the program's inner spans stay off the recorder
-    assert "session.submit.init" not in names
+    assert "session.submit.program" not in names
 
 
 def test_digest_counts_the_calls(tmp_path):
@@ -104,9 +105,9 @@ def test_digest_counts_the_calls(tmp_path):
         sched.schedule_step()
     d = trace.digest()
     spans = d["spans"]
-    calls = {"session.submit": 3, "session.submit.init": 3,
-             "session.submit.write": 3, "session.detach": 3,
-             "session.detach.read": 3, "session.detach.reset": 3,
+    calls = {"session.submit": 3, "session.submit.program": 3,
+             "session.detach": 3, "session.detach.program": 3,
+             "session.detach.read": 3,
              "session.poll": 1, "session.run": 1,
              "session.run.chunk": m.host_syncs,
              "session.run.chunk.wait": m.host_syncs,
@@ -116,13 +117,20 @@ def test_digest_counts_the_calls(tmp_path):
         assert 0 <= s["self_s"] <= s["total_s"]
         assert 0 < s["max_s"] <= s["total_s"]
     sub = spans["session.submit"]
-    kids = (spans["session.submit.init"]["total_s"]
-            + spans["session.submit.write"]["total_s"])
+    kids = spans["session.submit.program"]["total_s"]
     assert sub["self_s"] == pytest.approx(sub["total_s"] - kids, abs=1e-9)
+    det = spans["session.detach"]
+    kids = (spans["session.detach.program"]["total_s"]
+            + spans["session.detach.read"]["total_s"])
+    assert det["self_s"] == pytest.approx(det["total_s"] - kids, abs=1e-9)
     # blocking reads: one per chunk, the readout, the poll (one view) and
     # one per detached result
     assert d["counters"]["device_reads"] == m.host_syncs + 1 + 1 + 3
     assert d["counters"]["pairs_streamed"] == m.pairs_streamed > 0
+    # one admit or retire program a job's submit and detach; all compiled
+    # by the first story, outside the trace
+    assert d["counters"]["slot_programs"] == 3 + 3
+    assert d["counters"]["slot_compiles"] == 0
     trace.reset_digest()
     assert trace.digest() == {"spans": {}, "counters": {}}
 
@@ -159,10 +167,9 @@ def test_spans_nest_on_the_xplane_host_plane(tmp_path):
         by.setdefault(ev[0], []).append(ev)
     assert len(by["repro.session.submit"]) == 3
     assert len(by["repro.session.detach"]) == 3
-    nest = {"repro.session.submit.init": "repro.session.submit",
-            "repro.session.submit.write": "repro.session.submit",
+    nest = {"repro.session.submit.program": "repro.session.submit",
+            "repro.session.detach.program": "repro.session.detach",
             "repro.session.detach.read": "repro.session.detach",
-            "repro.session.detach.reset": "repro.session.detach",
             "repro.session.run.chunk": "repro.session.run",
             "repro.session.run.chunk.wait": "repro.session.run.chunk",
             "repro.session.run.readout": "repro.session.run"}
